@@ -18,15 +18,9 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.core.network import make_network
-from repro.core.online_controller import ProposalStrategy
-from repro.core.simulator import Simulator
-from repro.microservice.partition import (decompose, profile_stage_ms,
-                                          to_application)
+from repro.launch.serve import paper_loop
 from repro.models import build_model
 from repro.serving.engine import Request, ServingEngine
 
@@ -36,30 +30,15 @@ def main():
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
 
-    # ---- 1-2: decompose + profile real stage latencies ----------------
-    stages = decompose(cfg, n_core_stages=2)
-    b, s = 4, 32
-    batch = {"tokens": jnp.ones((b, s), jnp.int32)}
-    fwd = jax.jit(lambda p, bt: model.forward(p, bt)[0])
-    full_ms = profile_stage_ms(fwd, params, batch)
-    measured = {
-        "tokenize": 0.05, "sample": 0.10, "detokenize": 0.05,
-        "stage0": full_ms / 2, "stage1": full_ms / 2,
-    }
+    # ---- 1-3: decompose, profile real stage latencies, and let the
+    # paper machinery schedule the microservices -----------------------
+    loop = paper_loop(cfg, model, params, seed=0, profile_batch=(4, 32),
+                      horizon_slots=40, drain_slots=300)
     print("measured stage latencies (ms):",
-          {k: round(v, 2) for k, v in measured.items()})
-
-    # ---- 3: paper machinery schedules the microservices ----------------
-    rng = np.random.default_rng(0)
-    app = to_application(cfg, stages, rng, measured_ms=measured,
-                         deadline_ms=80.0, rate=0.3)
-    net = make_network(rng)
-    strat = ProposalStrategy(kappa=4)
-    sim = Simulator(app, net, strat, rng=np.random.default_rng(1),
-                    horizon_slots=40, drain_slots=300)
-    m = sim.run()
-    print("placement:", {app.ms(mm).name: int(xv.sum())
-                         for mm, xv in sim.x_cr.items()})
+          {k: round(v, 2) for k, v in loop.stage_ms.items()})
+    print("placement:", {loop.app.ms(mm).name: int(xv.sum())
+                         for mm, xv in loop.sim.x_cr.items()})
+    m = loop.metrics
     print(f"edge sim: on_time={m['on_time']:.3f} "
           f"completed={m['completed']:.3f} cost={m['total_cost']:.0f}")
 
@@ -74,7 +53,8 @@ def main():
     dt = time.perf_counter() - t0
     toks = sum(len(r.out_tokens) for r in done)
     print(f"served {len(done)} requests, {toks} tokens "
-          f"in {dt:.2f}s ({toks/dt:.1f} tok/s on CPU)")
+          f"in {dt:.2f}s ({toks/dt:.1f} tok/s on "
+          f"{jax.devices()[0].platform})")
 
 
 if __name__ == "__main__":

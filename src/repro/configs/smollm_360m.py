@@ -1,4 +1,4 @@
-"""SmolLM-360M — llama-arch small dense model. [hf:HuggingFaceTB/SmolLM-135M]"""
+"""SmolLM-360M — llama-arch small dense model. [hf:HuggingFaceTB/SmolLM-360M]"""
 from repro.config import ModelConfig, uniform
 
 CONFIG = ModelConfig(
@@ -14,5 +14,5 @@ CONFIG = ModelConfig(
     block_pattern=uniform("attn", 32),
     mlp_kind="dense",
     tie_embeddings=True,
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
 )

@@ -16,7 +16,7 @@ probability eps at parallelism y is
     g_{m,eps}(y) = workload_scaled * min_theta D(theta)
     D(theta) = ln(E_c(theta) / (eps * E[f/y])) / (theta * E_c(theta))
 
-We precompute the min over a log-spaced theta grid (vectorized in jnp) —
+We precompute the min over a log-spaced theta grid (vectorized in numpy) —
 this is the paper's "pre-calculated deterministic mapping".
 """
 from __future__ import annotations
@@ -24,11 +24,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import numpy as np
-
-try:  # jnp for the vectorized grid; falls back to numpy transparently
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jnp = np
 
 THETA_GRID = np.logspace(-3.0, 2.5, 160)
 

@@ -4,7 +4,8 @@ Two layouts share the running-softmax structure (grid (batch*heads,
 num_s_blocks), cache blocks innermost, (m, l, acc) in VMEM scratch):
 
 * :func:`decode_attention_pallas` — dense contiguous caches
-  (B, KV, S, D); the per-batch valid length (`pos`) masks stale slots.
+  (B, KV, S, D); the per-batch valid length (`pos`, in scalar prefetch)
+  masks stale slots.
 * :func:`paged_decode_attention_pallas` — block-pool caches
   (KV, NB, bs, D) addressed through per-request block tables
   (`models/kvcache.py`).  The tables and `pos` ride in scalar prefetch
@@ -35,8 +36,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *,
-                   scale: float, block_s: int, seq_len: int,
-                   batch: int, heads: int):
+                   scale: float, block_s: int, seq_len: int, heads: int):
     bh = pl.program_id(0)
     si = pl.program_id(1)
     ns = pl.num_programs(1)
@@ -89,26 +89,29 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, scale=None,
     vf = v_cache.reshape(b * kv, s, d)
 
     kernel = functools.partial(
-        _decode_kernel, scale=scale, block_s=block_s, seq_len=s,
-        batch=b, heads=h)
+        _decode_kernel, scale=scale, block_s=block_s, seq_len=s, heads=h)
 
-    out = pl.pallas_call(
-        kernel,
+    # pos rides in scalar prefetch (SMEM): a (B,) int32 VMEM block read
+    # at a dynamic row is refused by the TPU compiler (unaligned index)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b * h, ns),
         in_specs=[
-            # pos: whole (B,) vector visible to every program instance
-            pl.BlockSpec((b,), lambda bh, si: (0,)),
-            pl.BlockSpec((1, 1, d), lambda bh, si: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda bh, si, pos: (bh, 0, 0)),
             pl.BlockSpec((1, block_s, d),
-                         lambda bh, si, g=g: (bh // g, si, 0)),
+                         lambda bh, si, pos, g=g: (bh // g, si, 0)),
             pl.BlockSpec((1, block_s, d),
-                         lambda bh, si, g=g: (bh // g, si, 0)),
+                         lambda bh, si, pos, g=g: (bh // g, si, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bh, si: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, d), lambda bh, si, pos: (bh, 0, 0)),
         scratch_shapes=[
             pl_scratch((1, 1)), pl_scratch((1, 1)), pl_scratch((1, d)),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
         interpret=interpret,
     )(pos.astype(jnp.int32), qf, kf, vf)
     return out.reshape(b, h, d)

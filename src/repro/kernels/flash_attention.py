@@ -16,6 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -115,9 +116,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def pl_scratch(shape):
-    """VMEM scratch accumulator (TPU); plain array in interpret mode."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, jnp.float32)
+    """f32 VMEM scratch accumulator (interpret mode emulates it)."""
+    return pltpu.VMEM(shape, jnp.float32)

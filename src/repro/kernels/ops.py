@@ -1,14 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute with interpret=True for
-correctness validation; on TPU set REPRO_PALLAS_COMPILE=1 (or pass
-interpret=False) to compile for real.  Each op falls back to the ref.py
-oracle with use_pallas=False.
+On the CPU backend kernels run in the Pallas interpreter (interpret
+mode, for correctness tests); on any other backend they compile.  An
+explicit ``interpret=`` overrides that choice.  Each op falls back to
+the ref.py oracle with use_pallas=False.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +21,7 @@ from repro.kernels.selective_scan import selective_scan_pallas
 
 
 def _interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE"):
-        return False
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window",

@@ -42,9 +42,10 @@ def _qmm_int8_kernel(x_ref, q_ref, s_ref, o_ref):
 
 
 def _qmm_int4_kernel(x_ref, q_ref, s_ref, o_ref, *, group: int):
-    packed = q_ref[...]                          # (K//2, bn) uint8
-    lo = (packed & 0xF).astype(jnp.int8) - 8
-    hi = (packed >> 4).astype(jnp.int8) - 8
+    # unpack in int32: the TPU compiler cannot lower 8-bit subtraction
+    packed = q_ref[...].astype(jnp.int32)        # (K//2, bn)
+    lo = (packed & 0xF) - 8
+    hi = (packed >> 4) - 8
     k2, bn = packed.shape
     w = jnp.stack([lo, hi], axis=1).reshape(2 * k2, bn).astype(jnp.float32)
     w = w * jnp.repeat(s_ref[...], group, axis=0)

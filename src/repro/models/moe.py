@@ -129,7 +129,6 @@ def moe_apply_sharded(params: dict, x: jnp.ndarray, cfg,
     multi-GB gathers — see EXPERIMENTS.md §Perf.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_model = mesh.shape["model"]
@@ -193,13 +192,13 @@ def moe_apply_sharded(params: dict, x: jnp.ndarray, cfg,
         dropped = jnp.sum(~keep) / (t * k)
         return (y.reshape(bl, sl, d).astype(xl.dtype), aux_loss, dropped)
 
-    y, aux_loss, dropped = shard_map(
+    y, aux_loss, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_axes, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(b_axes, None, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["we_gate"], params["we_up"],
       params["we_down"])
     return y, {"moe_aux_loss": aux_loss, "moe_drop_frac": dropped}
@@ -217,7 +216,6 @@ def moe_apply_capsharded(params: dict, x: jnp.ndarray, cfg,
     which could not shard an 8-long expert dim over 16 ranks.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_model = mesh.shape["model"]
@@ -278,13 +276,13 @@ def moe_apply_capsharded(params: dict, x: jnp.ndarray, cfg,
         dropped = jnp.sum(~keep) / (t * k)
         return (y.reshape(bl, sl, d).astype(xl.dtype), aux_loss, dropped)
 
-    y, aux_loss, dropped = shard_map(
+    y, aux_loss, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_axes, None, None), P(None, None),
                   P(None, None, None), P(None, None, None),
                   P(None, None, None)),
         out_specs=(P(b_axes, None, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["we_gate"], params["we_up"],
       params["we_down"])
     return y, {"moe_aux_loss": aux_loss, "moe_drop_frac": dropped}

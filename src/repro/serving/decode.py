@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -66,10 +65,10 @@ def distributed_decode_attention(q, k_cache, v_cache, pos, mesh: Mesh,
         l = jax.lax.psum(l * corr, axis)
         return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(ba, None, None), P(ba, None, axis, None),
                   P(ba, None, axis, None), P(ba)),
         out_specs=P(ba, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, pos)

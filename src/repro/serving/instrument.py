@@ -117,8 +117,8 @@ class EngineCounts:
         see other engines' compiles — absolute assertions need a cold
         cache (``jax.clear_caches()``), as test_engine_macro.py does.
         Entries without a compilation cache (e.g. a FakeEngine's plain
-        callables, or an unexpectedly old jax) contribute zero, so a
-        result of 0 means 'nothing measurable', not 'no compiles'."""
+        callables) contribute zero, so a result of 0 means 'nothing
+        measurable', not 'no compiles'."""
         return sum(fn._cache_size() for fn in self.raw.values()
                    if hasattr(fn, "_cache_size"))
 

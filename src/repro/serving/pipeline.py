@@ -26,7 +26,7 @@ engine — composition of ``run_stages`` over consecutive ranges
 reproduces the forward op-for-op); the network is simulated (hop delays
 are accounted, not slept).  Chunked prefill and profiling run one
 jitted program per stage; the decode hot loop chains every stage inside
-one fused, donated macro-step scan (``_NetShimMixin._macro_jit``,
+one fused, donated macro-step scan (:func:`macro_step`,
 SERVING.md §The decode hot loop) while the per-hop accounting stays
 per device step.  Light services are accounted at fixed homes:
 tokenize/detokenize at the entry node, sample co-located with the exit
@@ -47,7 +47,7 @@ stages only.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +110,37 @@ def place_stages(app, net, strategy: str = "static_ip", *, kappa: int = 2,
         return {app.ms(m).name: int(rng.choice(es)) for m in core}
     raise ValueError(f"unknown placement strategy {strategy!r}; "
                      f"known: {PLACEMENT_STRATEGIES}")
+
+
+def macro_step(model, ranges: List[Tuple[int, int]], k: int):
+    """The pipelined engine's fused K-step decode, unjitted: one
+    ``lax.scan`` whose body runs the layer ``ranges`` in order, then
+    greedy argmax / token feedback / pos bump / budget masking.  Takes
+    ``(params_list, caches_list, tok, pos, budget, pmeta=None)`` with one
+    params / caches entry per range; returns ``(tokens (B, k),
+    caches_list)``."""
+    vocab = model.cfg.vocab_size
+
+    def run(params_list, caches_list, tok, pos, budget, pmeta=None):
+        def body(carry, _):
+            caches_list, tok, pos, budget = carry
+            x = tok
+            new_list = []
+            for p, c, (lo, hi) in zip(params_list, caches_list, ranges):
+                x, nc, _ = model.run_stages(
+                    p, x, lo, hi, mode="decode", pos=pos, caches=c,
+                    paged=pmeta)
+                new_list.append(nc)
+            tok, pos, budget, emit = greedy_scan_update(
+                x, pos, budget, vocab)
+            return (new_list, tok, pos, budget), emit
+
+        carry = (caches_list, tok, pos, budget)
+        (caches_list, _, _, _), toks = jax.lax.scan(
+            body, carry, None, length=k)
+        return jnp.transpose(toks), caches_list
+
+    return run
 
 
 class _CoreStage:
@@ -323,31 +354,8 @@ class _NetShimMixin:
         """
         key = f"decode{k}"
         if key not in self._jits:
-            model = self.model
-            ranges = [(st.lo, st.hi) for st in self.stages]
-            vocab = self.cfg.vocab_size
-
-            def run(params_list, caches_list, tok, pos, budget,
-                    pmeta=None):
-                def body(carry, _):
-                    caches_list, tok, pos, budget = carry
-                    x = tok
-                    new_list = []
-                    for p, c, (lo, hi) in zip(params_list, caches_list,
-                                              ranges):
-                        x, nc, _ = model.run_stages(
-                            p, x, lo, hi, mode="decode", pos=pos,
-                            caches=c, paged=pmeta)
-                        new_list.append(nc)
-                    tok, pos, budget, emit = greedy_scan_update(
-                        x, pos, budget, vocab)
-                    return (new_list, tok, pos, budget), emit
-
-                carry = (caches_list, tok, pos, budget)
-                (caches_list, _, _, _), toks = jax.lax.scan(
-                    body, carry, None, length=k)
-                return jnp.transpose(toks), caches_list
-
+            run = macro_step(self.model,
+                             [(st.lo, st.hi) for st in self.stages], k)
             self._jits[key] = jax.jit(run, donate_argnums=(1,))
         return self._jits[key]
 

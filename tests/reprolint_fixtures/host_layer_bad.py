@@ -5,7 +5,7 @@ from jax import lax              # EXPECT: host-layer-jax
 
 
 def nested():
-    from jax.experimental import shard_map  # EXPECT: host-layer-jax
+    from jax import shard_map  # EXPECT: host-layer-jax
     return shard_map
 
 

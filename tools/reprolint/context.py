@@ -44,7 +44,7 @@ _TRACED_CALLEE_ARGS = {
     "jax.lax.fori_loop": (2,),
     "jax.lax.cond": (1, 2),
     "jax.lax.switch": None,  # every arg from 1 on
-    "jax.experimental.shard_map.shard_map": (0,),
+    "jax.shard_map": (0,),
     "jax.checkpoint": (0,),
     "jax.remat": (0,),
     "jax.vmap": (0,),
