@@ -60,10 +60,16 @@ engines (benchmarks/paged_bench.py reports both).  Requests that can
 never be served (prompt + max_new_tokens over capacity) are rejected
 with ``Request.error`` set — they land in ``engine.rejected``, never
 killing the engine.
+
+The step counter is the only clock the engine decides by.  Its spans
+and request marks (``self.trace``: an ``EngineTrace`` attached by
+`serving/instrument.py`, else a null trace that records nothing) are
+stamped on a clock the *caller* injects, so no wall-clock read lives
+here, and the trace only records: no admission, growth, preemption or
+scan-length decision reads it.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -74,6 +80,7 @@ import numpy as np
 from repro.models import build_model, quantize_params
 from repro.models.kvcache import (PagedCache, paged_copy_blocks,
                                   paged_reset_row)
+from repro.serving.instrument import NULL_TRACE
 from repro.serving.scheduler import (DEFER, REJECT, CapacityView,
                                      make_policy)
 from repro.serving.speculative import SpecConfig, spec_supported
@@ -177,12 +184,15 @@ class _EngineBase:
         self.prefill_tokens = 0    # tokens actually prefilled (a prefix
         #                            hit shrinks this: the engine's
         #                            admission-cost / t_first budget)
+        # spans and request marks (serving/instrument.py); records only
+        self.trace = NULL_TRACE
 
     def submit(self, req: Request):
         if req.t_submit is None:  # resubmission keeps the original stamp
             req.t_submit = self.t
         self.queue.append(req)
         self.policy.on_submit(req, self.t)
+        self.trace.mark(req.id, "submit")
 
     def _reject(self, req: Request, msg: str):
         """Fail one request without killing the engine (an oversized
@@ -192,17 +202,24 @@ class _EngineBase:
         req.t_done = self.t
         self.rejected.append(req)
 
-    def _prefill_chunks(self, row: int, toks: List[int], pos0: int = 0):
+    def _prefill_chunks(self, req: Request, row: int, toks: List[int],
+                        pos0: int = 0):
         """Chunked prefill of one admitted request through the
         ``_prefill_row`` hook.  ``pos0`` offsets the absolute positions
         — a prefix-cache hit prefills only the tail beyond the shared
-        span (the skipped span never costs a prefill dispatch)."""
+        span (the skipped span never costs a prefill dispatch).  Each
+        chunk's ``engine.prefill_prep`` span holds the chunk's input
+        building as its own time and the ``prefill`` program's dispatch
+        as its child."""
+        tr = self.trace
         i = 0
         for c in chunk_sizes(len(toks), self.prefill_chunk):
-            self._prefill_row(row, np.asarray(toks[i:i + c],
-                                              dtype=np.int32), pos0 + i)
+            with tr.span("engine.prefill_prep"):
+                self._prefill_row(row, np.asarray(toks[i:i + c],
+                                                  dtype=np.int32), pos0 + i)
             i += c
         self.prefill_tokens += len(toks)
+        tr.mark(req.id, "prefilled")
 
     def _next_tokens(self, width: int, active: List[int],
                      store: List[Optional[Request]]) -> np.ndarray:
@@ -245,27 +262,36 @@ class _EngineBase:
         if k_cap is not None and k_eff > k_cap:
             k_eff = 1 << (k_cap.bit_length() - 1)  # largest pow2 <= cap
             budgets = np.minimum(budgets, k_eff)
-        tokens = self._next_tokens(len(store), active, store)
-        # pos is snapshotted before handing to jax: jnp.asarray aliases
-        # numpy buffers on CPU and the jitted scan dispatches
-        # asynchronously, so the += below must not race it
-        out = self._forward_steps(tokens, self.pos.copy(), budgets, k_eff)
+        tr = self.trace
+        with tr.span("engine.decode_prep"):
+            tokens = self._next_tokens(len(store), active, store)
+            # pos is snapshotted before handing to jax: jnp.asarray
+            # aliases numpy buffers on CPU and the jitted scan dispatches
+            # asynchronously, so the += below must not race it
+            out = self._forward_steps(tokens, self.pos.copy(), budgets,
+                                      k_eff)
+        with tr.span("engine.sync"):
+            # reprolint: disable-next=host-sync -- the ONE deliberate sync
+            # per macro-step (counted in n_host_syncs; <= 1/K per token)
+            out = np.asarray(out)
         self.n_host_syncs += 1
         self.max_macro_tokens = max(self.max_macro_tokens,
                                     int(budgets.sum()))
         finished = []
-        for i in active:
-            req = store[i]
-            v = int(budgets[i])
-            if v > 0 and req.t_first is None and not req.out_tokens:
-                req.t_first = t0 + 1  # first token lands on device step 1
-            req.out_tokens += [int(t) for t in out[i, :v]]
-            self.tokens_generated += v
-            self.pos[i] += v
-            if req.done or self.pos[i] >= max_len - 1:
-                req.t_done = t0 + v
-                finished.append((i, req))
-                self.policy.on_done(req, t0 + v)
+        with tr.span("engine.deliver"):
+            for i in active:
+                req = store[i]
+                v = int(budgets[i])
+                if v > 0 and req.t_first is None and not req.out_tokens:
+                    req.t_first = t0 + 1  # first token on device step 1
+                    tr.mark(req.id, "first")
+                req.out_tokens += [int(t) for t in out[i, :v]]
+                self.tokens_generated += v
+                self.pos[i] += v
+                if req.done or self.pos[i] >= max_len - 1:
+                    req.t_done = t0 + v
+                    finished.append((i, req))
+                    self.policy.on_done(req, t0 + v)
         self.t = t0 + k_eff
         return finished
 
@@ -276,9 +302,14 @@ class _EngineBase:
         ``_NetShimMixin._macro_jit``)."""
         key = f"decode{k}"
         if key not in self._jits:
-            self._jits[key] = jax.jit(
-                functools.partial(self.model.decode_steps, k=k),
-                donate_argnums=(1,))
+            model = self.model
+
+            # a named function, so the compiled module is called
+            # ``jit_decode_steps`` in profiles (a partial has no name)
+            def decode_steps(params, caches, batch, paged=None):
+                return model.decode_steps(params, caches, batch, paged, k=k)
+
+            self._jits[key] = jax.jit(decode_steps, donate_argnums=(1,))
         return self._jits[key]
 
     # ------------------------------------------------------------------
@@ -328,48 +359,57 @@ class _EngineBase:
         """
         K = self.spec.k
         width = len(store)
-        tokens = np.zeros((width, K + 1), dtype=np.int32)
-        tokens[:, :1] = self._next_tokens(width, active, store)
-        for i in active:
-            req = store[i]
-            tokens[i, 1:] = self.spec.provider.propose(
-                i, req.prompt + req.out_tokens, K)
-            self.spec_drafted += K
-        out = self._forward_verify(tokens, self.pos.copy(), budgets)
+        tr = self.trace
+        with tr.span("engine.decode_prep"):
+            tokens = np.zeros((width, K + 1), dtype=np.int32)
+            tokens[:, :1] = self._next_tokens(width, active, store)
+            for i in active:
+                req = store[i]
+                tokens[i, 1:] = self.spec.provider.propose(
+                    i, req.prompt + req.out_tokens, K)
+                self.spec_drafted += K
+            out = self._forward_verify(tokens, self.pos.copy(), budgets)
+        with tr.span("engine.sync"):
+            # reprolint: disable-next=host-sync -- the ONE deliberate sync
+            # per verify round (counted in n_host_syncs; <= 1 per token)
+            out = np.asarray(out)
         self.n_host_syncs += 1
         self.max_macro_tokens = max(self.max_macro_tokens,
                                     int(budgets.sum()))
         self.spec_rounds += 1
         finished = []
-        for i in active:
-            req = store[i]
-            row = out[i]
-            v = int((row >= 0).sum())  # accepted length + 1, <= budget
-            if v > 0 and req.t_first is None and not req.out_tokens:
-                req.t_first = t0 + 1  # the round is one device step
-            emitted = [int(t) for t in row[:v]]
-            # matched drafts ARE the emitted tokens; the correction
-            # token (if emitted) differs from its draft by construction
-            self.spec_accepted += sum(
-                1 for j in range(min(v, K))
-                if emitted[j] == int(tokens[i, 1 + j]))
-            self.spec_emitted += v
-            self._spec_row_rounds += 1
-            req.out_tokens += emitted
-            self.tokens_generated += v
-            self.pos[i] += v
-            if req.done or self.pos[i] >= max_len - 1:
-                req.t_done = t0 + 1
-                finished.append((i, req))
-                self.policy.on_done(req, t0 + 1)
+        with tr.span("engine.deliver"):
+            for i in active:
+                req = store[i]
+                row = out[i]
+                v = int((row >= 0).sum())  # accepted length + 1 <= budget
+                if v > 0 and req.t_first is None and not req.out_tokens:
+                    req.t_first = t0 + 1  # the round is one device step
+                    tr.mark(req.id, "first")
+                emitted = [int(t) for t in row[:v]]
+                # matched drafts ARE the emitted tokens; the correction
+                # token (if emitted) differs from its draft by construction
+                self.spec_accepted += sum(
+                    1 for j in range(min(v, K))
+                    if emitted[j] == int(tokens[i, 1 + j]))
+                self.spec_emitted += v
+                self._spec_row_rounds += 1
+                req.out_tokens += emitted
+                self.tokens_generated += v
+                self.pos[i] += v
+                if req.done or self.pos[i] >= max_len - 1:
+                    req.t_done = t0 + 1
+                    finished.append((i, req))
+                    self.policy.on_done(req, t0 + 1)
         self.t = t0 + 1
         return finished
 
     def _forward_verify(self, tokens: np.ndarray, pos: np.ndarray,
-                        budgets: np.ndarray) -> np.ndarray:
+                        budgets: np.ndarray):
         """One fused draft-verify round over the (rows, K+1) chunk
         ``[next input, K drafts]``.  Returns (rows, K+1) int32 emitted
-        tokens, -1 in non-emitted slots."""
+        tokens, -1 in non-emitted slots, still on the device: the
+        caller's one sync per round brings them to the host."""
         raise NotImplementedError  # pragma: no cover - interface
 
     def step(self, k_cap: Optional[int] = None) -> List[Request]:
@@ -407,9 +447,11 @@ class _EngineBase:
         raise NotImplementedError  # pragma: no cover - interface
 
     def _forward_steps(self, tokens: np.ndarray, pos: np.ndarray,
-                       budgets: np.ndarray, k: int) -> np.ndarray:
+                       budgets: np.ndarray, k: int):
         """One fused macro-step of ``k`` device decode iterations.
-        Returns (rows, k) int32 token ids (row r valid to budgets[r])."""
+        Returns (rows, k) int32 token ids (row r valid to budgets[r]),
+        still on the device: :meth:`_macro_tail`'s one sync per
+        macro-step brings them to the host."""
         raise NotImplementedError  # pragma: no cover - interface
 
 
@@ -490,10 +532,11 @@ class _SlotEngine(_EngineBase):
             self.queue.remove(req)
             if req.t_admit is None:
                 req.t_admit = self.t
+            self.trace.mark(req.id, "admit")
             self.slots[slot] = req
             self._reset_row(slot)
             toks = req.prompt[:-1]
-            self._prefill_chunks(slot, toks)
+            self._prefill_chunks(req, slot, toks)
             self.pos[slot] = len(toks)
 
     # ------------------------------------------------------------------
@@ -502,39 +545,43 @@ class _SlotEngine(_EngineBase):
         ``decode_k`` batched decode iterations (``k_cap`` further bounds
         the device steps — the run loop's remaining budget).  Returns
         finished requests."""
-        t0 = self.t
-        self.t += 1  # admission/rejection stamps land on the first step
-        self.policy.on_step(self.t, self.queue, self._in_flight())
-        self._admit()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return []
-        # a speculative round emits up to K+1 tokens per row in ONE
-        # device step, so its budget is token-denominated (drafts + the
-        # correction/bonus token), not scan-step-denominated
-        k = (self.spec.k + 1 if self.spec is not None
-             else self.decode_k if k_cap is None
-             else max(1, min(self.decode_k, k_cap)))
-        # per-row step budget: never decode past max_new_tokens or the
-        # cache-headroom stop (pos >= cache_len - 1) inside the scan
-        budgets = np.zeros(self.max_batch, dtype=np.int32)
-        for i in active:
-            req = self.slots[i]
-            budgets[i] = max(1, min(
-                k, req.max_new_tokens - len(req.out_tokens),
-                self.cache_len - 1 - int(self.pos[i])))
-        if self.spec is not None:
-            finished = self._spec_tail(self.slots, budgets, active,
-                                       self.cache_len, t0)
-        else:
-            finished = self._macro_tail(self.slots, budgets, active,
-                                        self.cache_len, t0, k_cap=k_cap)
-        done = []
-        for i, req in finished:
-            self.slots[i] = None
-            self.policy.on_free(1, self.t)  # one slot granule returned
-            done.append(req)
-        return done
+        tr = self.trace
+        with tr.span("engine.step"):
+            t0 = self.t
+            self.t += 1  # admission/rejection stamps land on the 1st step
+            self.policy.on_step(self.t, self.queue, self._in_flight())
+            with tr.span("engine.admit"):
+                self._admit()
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            if not active:
+                return []
+            # a speculative round emits up to K+1 tokens per row in ONE
+            # device step, so its budget is token-denominated (drafts +
+            # the correction/bonus token), not scan-step-denominated
+            k = (self.spec.k + 1 if self.spec is not None
+                 else self.decode_k if k_cap is None
+                 else max(1, min(self.decode_k, k_cap)))
+            # per-row step budget: never decode past max_new_tokens or
+            # the cache-headroom stop (pos >= cache_len - 1) in the scan
+            budgets = np.zeros(self.max_batch, dtype=np.int32)
+            for i in active:
+                req = self.slots[i]
+                budgets[i] = max(1, min(
+                    k, req.max_new_tokens - len(req.out_tokens),
+                    self.cache_len - 1 - int(self.pos[i])))
+            if self.spec is not None:
+                finished = self._spec_tail(self.slots, budgets, active,
+                                           self.cache_len, t0)
+            else:
+                finished = self._macro_tail(self.slots, budgets, active,
+                                            self.cache_len, t0, k_cap=k_cap)
+            done = []
+            with tr.span("engine.release"):
+                for i, req in finished:
+                    self.slots[i] = None
+                    self.policy.on_free(1, self.t)  # one slot granule back
+                    done.append(req)
+            return done
 
 
 class _PagedEngine(_EngineBase):
@@ -653,6 +700,7 @@ class _PagedEngine(_EngineBase):
                     f"(row {row}, {total} tokens)")
             if req.t_admit is None:
                 req.t_admit = self.t
+            self.trace.mark(req.id, "admit")
             self.rows[row] = req
             self._admit_order.append(row)
             self._reset_row(row)
@@ -661,7 +709,7 @@ class _PagedEngine(_EngineBase):
             # (hit == len(toks) skips prefill entirely: decode input is
             # the last token itself, not a prefill output)
             hit = self.pc.hit_tokens(row)
-            self._prefill_chunks(row, toks[hit:], pos0=hit)
+            self._prefill_chunks(req, row, toks[hit:], pos0=hit)
             self.pos[row] = len(toks)
 
     def _preempt(self, row: int):
@@ -743,49 +791,56 @@ class _PagedEngine(_EngineBase):
         macro-step of up to ``decode_k`` decode iterations (``k_cap``
         further bounds the device steps — the run loop's remaining
         budget).  Returns finished requests."""
-        t0 = self.t
-        self.t += 1  # admission/rejection stamps land on the first step
-        self.policy.on_step(self.t, self.queue, self._in_flight())
-        self._admit()
-        # a speculative round's budget is token-denominated (see
-        # _SlotEngine.step): _grow covers up to K+1 writes per row
-        k = (self.spec.k + 1 if self.spec is not None
-             else self.decode_k if k_cap is None
-             else max(1, min(self.decode_k, k_cap)))
-        budgets, clip = self._grow(k)
-        # any copy-on-write the ledger queued (a row about to write a
-        # still-shared block) must hit the device pools before the scan
-        # reads or writes the fresh copies
-        pairs = self.pc.take_pending_copies()
-        if pairs:
-            self._apply_cow(pairs)
-        active = [i for i, r in enumerate(self.rows) if r is not None]
-        if not active:
-            return []
-        if self.spec is not None:
-            # clip needs no special handling: emission clamps to the
-            # block-covered budget, the verify chunk's writes beyond it
-            # land in the scratch block (never read below the accepted
-            # length), and the SSM-resume hazard clip guards against
-            # cannot occur — speculation is gated to pure-attention archs
-            finished = self._spec_tail(self.rows, budgets, active,
-                                       self.max_len, t0)
-        else:
-            caps = [c for c in (clip, k_cap) if c is not None]
-            cap = min(caps) if caps else None
-            finished = self._macro_tail(self.rows, budgets, active,
-                                        self.max_len, t0, k_cap=cap)
-        done = []
-        for i, req in finished:
-            self.rows[i] = None
-            self._admit_order.remove(i)
-            fb0 = self.pc.free_blocks
-            self.pc.release(i)
-            # completion releases feed the EC policy's service model
-            # (preemption frees are churn, not service — not counted)
-            self.policy.on_free(self.pc.free_blocks - fb0, self.t)
-            done.append(req)
-        return done
+        tr = self.trace
+        with tr.span("engine.step"):
+            t0 = self.t
+            self.t += 1  # admission/rejection stamps land on the 1st step
+            self.policy.on_step(self.t, self.queue, self._in_flight())
+            with tr.span("engine.admit"):
+                self._admit()
+            # a speculative round's budget is token-denominated (see
+            # _SlotEngine.step): _grow covers up to K+1 writes per row
+            k = (self.spec.k + 1 if self.spec is not None
+                 else self.decode_k if k_cap is None
+                 else max(1, min(self.decode_k, k_cap)))
+            with tr.span("engine.grow"):
+                budgets, clip = self._grow(k)
+            # any copy-on-write the ledger queued (a row about to write
+            # a still-shared block) must hit the device pools before the
+            # scan reads or writes the fresh copies
+            with tr.span("engine.cow"):
+                pairs = self.pc.take_pending_copies()
+                if pairs:
+                    self._apply_cow(pairs)
+            active = [i for i, r in enumerate(self.rows) if r is not None]
+            if not active:
+                return []
+            if self.spec is not None:
+                # clip needs no special handling: emission clamps to the
+                # block-covered budget, the verify chunk's writes beyond
+                # it land in the scratch block (never read below the
+                # accepted length), and the SSM-resume hazard clip
+                # guards against cannot occur — speculation is gated to
+                # pure-attention archs
+                finished = self._spec_tail(self.rows, budgets, active,
+                                           self.max_len, t0)
+            else:
+                caps = [c for c in (clip, k_cap) if c is not None]
+                cap = min(caps) if caps else None
+                finished = self._macro_tail(self.rows, budgets, active,
+                                            self.max_len, t0, k_cap=cap)
+            done = []
+            with tr.span("engine.release"):
+                for i, req in finished:
+                    self.rows[i] = None
+                    self._admit_order.remove(i)
+                    fb0 = self.pc.free_blocks
+                    self.pc.release(i)
+                    # completion releases feed the EC policy's service
+                    # model (preemption frees are churn, not service)
+                    self.policy.on_free(self.pc.free_blocks - fb0, self.t)
+                    done.append(req)
+            return done
 
     def _apply_cow(self, pairs: List[tuple]):
         """Apply queued COW pool copies ``[(src, dst), ...]`` to the
@@ -835,24 +890,20 @@ class ServingEngine(_SlotEngine):
             jnp.int32(pos0), jnp.int32(slot))
 
     def _forward_steps(self, tokens: np.ndarray, pos: np.ndarray,
-                       budgets: np.ndarray, k: int) -> np.ndarray:
+                       budgets: np.ndarray, k: int):
         toks, self.caches = self._decode_jit(k)(
             self.params, self.caches,
             {"token": jnp.asarray(tokens), "pos": jnp.asarray(pos),
              "budget": jnp.asarray(budgets)})
-        # reprolint: disable-next=host-sync -- the ONE deliberate sync
-        # per macro-step (counted in n_host_syncs; <= 1/K per token)
-        return np.asarray(toks)
+        return toks
 
     def _forward_verify(self, tokens: np.ndarray, pos: np.ndarray,
-                        budgets: np.ndarray) -> np.ndarray:
+                        budgets: np.ndarray):
         emit, self.caches = self._verify_jit(tokens.shape[1])(
             self.params, self.caches,
             {"token": jnp.asarray(tokens), "pos": jnp.asarray(pos),
              "budget": jnp.asarray(budgets)})
-        # reprolint: disable-next=host-sync -- the ONE deliberate sync
-        # per verify round (counted in n_host_syncs; <= 1 per token)
-        return np.asarray(emit)
+        return emit
 
 
 class PagedServingEngine(_PagedEngine):
@@ -915,23 +966,19 @@ class PagedServingEngine(_PagedEngine):
             jnp.int32(pos0), jnp.int32(row), self.pc.meta(row=row))
 
     def _forward_steps(self, tokens: np.ndarray, pos: np.ndarray,
-                       budgets: np.ndarray, k: int) -> np.ndarray:
+                       budgets: np.ndarray, k: int):
         toks, self.caches = self._decode_jit(k)(
             self.params, self.caches,
             {"token": jnp.asarray(tokens), "pos": jnp.asarray(pos),
              "budget": jnp.asarray(budgets)},
             self.pc.meta())
-        # reprolint: disable-next=host-sync -- the ONE deliberate sync
-        # per macro-step (counted in n_host_syncs; <= 1/K per token)
-        return np.asarray(toks)
+        return toks
 
     def _forward_verify(self, tokens: np.ndarray, pos: np.ndarray,
-                        budgets: np.ndarray) -> np.ndarray:
+                        budgets: np.ndarray):
         emit, self.caches = self._verify_jit(tokens.shape[1])(
             self.params, self.caches,
             {"token": jnp.asarray(tokens), "pos": jnp.asarray(pos),
              "budget": jnp.asarray(budgets)},
             self.pc.meta())
-        # reprolint: disable-next=host-sync -- the ONE deliberate sync
-        # per verify round (counted in n_host_syncs; <= 1 per token)
-        return np.asarray(emit)
+        return emit

@@ -360,9 +360,11 @@ class _NetShimMixin:
         return self._jits[key]
 
     def _run_macro(self, tokens: np.ndarray, pos: np.ndarray,
-                   budgets: np.ndarray, k: int, pmeta=None) -> np.ndarray:
+                   budgets: np.ndarray, k: int, pmeta=None):
         """Invoke the fused macro-step, rebind every stage's caches
-        (they were donated), and account the per-step network hops."""
+        (they were donated), and account the per-step network hops.
+        Returns the (rows, k) token ids on the device (the engine's one
+        sync per macro-step brings them to the host)."""
         params_list = [st.params for st in self.stages]
         caches_list = [st.caches for st in self.stages]
         args = (() if pmeta is None else (pmeta,))
@@ -372,9 +374,7 @@ class _NetShimMixin:
         for st, nc in zip(self.stages, new_caches):
             st.caches = nc
         self._account_macro(budgets, k)
-        # reprolint: disable-next=host-sync -- the ONE deliberate sync
-        # per macro-step (counted in n_host_syncs; <= 1/K per token)
-        return np.asarray(toks)
+        return toks
 
     def _account_macro(self, budgets: np.ndarray, k: int):
         """Simulated-network accounting for one macro-step: device step
@@ -427,9 +427,10 @@ class _NetShimMixin:
         return self._jits[key]
 
     def _run_verify(self, tokens: np.ndarray, pos: np.ndarray,
-                    budgets: np.ndarray, pmeta=None) -> np.ndarray:
+                    budgets: np.ndarray, pmeta=None):
         """Invoke the fused verify round, rebind every stage's caches
-        (they were donated), and account the per-round network hops."""
+        (they were donated), and account the per-round network hops.
+        Returns the emitted tokens on the device."""
         params_list = [st.params for st in self.stages]
         caches_list = [st.caches for st in self.stages]
         args = (() if pmeta is None else (pmeta,))
@@ -439,9 +440,7 @@ class _NetShimMixin:
         for st, nc in zip(self.stages, new_caches):
             st.caches = nc
         self._account_verify(budgets, tokens.shape[1])
-        # reprolint: disable-next=host-sync -- the ONE deliberate sync
-        # per verify round (counted in n_host_syncs; <= 1 per token)
-        return np.asarray(emit)
+        return emit
 
     def _account_verify(self, budgets: np.ndarray, s: int):
         """Simulated-network accounting for one verify round: every
@@ -518,11 +517,11 @@ class PipelinedEngine(_SlotEngine, _NetShimMixin):
             self._ship_between(k, c, self._act_bytes)
 
     def _forward_steps(self, tokens: np.ndarray, pos: np.ndarray,
-                       budgets: np.ndarray, k: int) -> np.ndarray:
+                       budgets: np.ndarray, k: int):
         return self._run_macro(tokens, pos, budgets, k)
 
     def _forward_verify(self, tokens: np.ndarray, pos: np.ndarray,
-                        budgets: np.ndarray) -> np.ndarray:
+                        budgets: np.ndarray):
         return self._run_verify(tokens, pos, budgets)
 
 
@@ -584,11 +583,11 @@ class PagedPipelinedEngine(_PagedEngine, _NetShimMixin):
             st.copy_blocks(src, dst)
 
     def _forward_steps(self, tokens: np.ndarray, pos: np.ndarray,
-                       budgets: np.ndarray, k: int) -> np.ndarray:
+                       budgets: np.ndarray, k: int):
         return self._run_macro(tokens, pos, budgets, k,
                                pmeta=self.pc.meta())
 
     def _forward_verify(self, tokens: np.ndarray, pos: np.ndarray,
-                        budgets: np.ndarray) -> np.ndarray:
+                        budgets: np.ndarray):
         return self._run_verify(tokens, pos, budgets,
                                 pmeta=self.pc.meta())
