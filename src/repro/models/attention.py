@@ -7,11 +7,20 @@ Decode paths are the body of the engines' fused macro-step
 (``Model.decode_steps``, a ``lax.scan`` carrying the cache): ``pos`` may
 be *frozen* for rows the scheduler has masked (a finished or empty batch
 row keeps re-writing its last slot from token 0 — the same ops the
-per-token host loop always ran for inactive rows), and under buffer
-donation the cache-in/cache-out pairs alias, so the ``.at[].set`` writes
-update the pools in place.  Both rely on the invariants documented in
-`src/repro/models/kvcache.py`: stale KV is position-masked, unallocated
-paged slots resolve to the never-read scratch block.
+per-token host loop always ran for inactive rows).  This relies on the
+invariants documented in `src/repro/models/kvcache.py`: stale KV is
+position-masked, unallocated paged slots resolve to the never-read
+scratch block.
+
+The paged paths take a segment's *stacked* pools (leading layer dim)
+and the layer's index in ``paged["layer"]``: the layer scan carries the
+pools (`src/repro/models/transformer.py::apply_segments`), each layer
+scatters its new K/V at ``pool.at[layer, phys, off]`` and gathers its
+view with one ``pool[layer, tables]``; a pool stores each slot as one
+row of ``kv_heads * head_dim``.  A loop carry updated by indexed
+scatters is what keeps the writes in place; buffer donation alone did
+not (a pool threaded through the scan as ``xs``/``ys`` was sliced out,
+written and stacked back, whole, every layer of every iteration).
 """
 from __future__ import annotations
 
@@ -237,14 +246,20 @@ def chunk_self_attention(params, x, cache: dict, pos, cfg,
 # stale/unallocated slot is masked exactly where the dense path masks
 # its zero-initialised slots.
 # ----------------------------------------------------------------------
-def _paged_gather(pool, tables, take: Optional[int] = None):
-    """pool (NB, bs, KV, hd) gathered through tables (B, nb) into the
-    logical view (B, nb*bs, KV, hd), optionally truncated to ``take``
-    slots (SWA ring / cross source shorter than the block grid)."""
-    g = pool[tables]                                 # (B, nb, bs, KV, hd)
+def _paged_gather(pool, layer, tables, cfg, take: Optional[int] = None):
+    """Layer ``layer`` of the stacked pool (L, NB, bs, KV*hd) gathered
+    through tables (B, nb) into the logical view (B, nb*bs, KV, hd),
+    optionally truncated to ``take`` slots (SWA ring / cross source
+    shorter than the block grid)."""
+    g = pool[layer, tables]                          # (B, nb, bs, KV*hd)
     b, nb, bs = g.shape[:3]
-    g = g.reshape(b, nb * bs, *g.shape[3:])
+    g = g.reshape(b, nb * bs, cfg.n_kv_heads, cfg.head_dim)
     return g if take is None else g[:, :take]
+
+
+def _slot_rows(kv):
+    """(..., KV, hd) new keys or values -> (..., KV*hd) pool slot rows."""
+    return kv.reshape(*kv.shape[:-2], -1)
 
 
 def _decode_valid(pos, s: int, ring: bool):
@@ -259,25 +274,29 @@ def _decode_valid(pos, s: int, ring: bool):
     return valid
 
 
-def paged_cross_view(cache: dict, paged: dict, src: int) -> dict:
-    """Cross-KV logical view of each row's cross blocks (zeroed at
-    admission, so this matches the dense engines' zero cross rows)."""
-    return {"k": _paged_gather(cache["xk"], paged["cross_tables"], src),
-            "v": _paged_gather(cache["xv"], paged["cross_tables"], src)}
+def paged_cross_view(cache: dict, paged: dict, src: int, cfg) -> dict:
+    """Cross-KV logical view of each row's cross blocks at layer
+    ``paged["layer"]`` (zeroed at admission, so this matches the dense
+    engines' zero cross rows).  Read-only: the pools pass through."""
+    layer, tables = paged["layer"], paged["cross_tables"]
+    return {"k": _paged_gather(cache["xk"], layer, tables, cfg, src),
+            "v": _paged_gather(cache["xv"], layer, tables, cfg, src)}
 
 
 def paged_decode_self_attention(params, x, cache: dict, paged: dict, pos,
                                 cfg, kind: str) -> Tuple[jnp.ndarray, dict]:
     """One-token decode against paged block pools.
 
-    x: (B,1,D); cache {"k","v"}: (NB_phys, bs, KV, hd) pools; paged
-    carries the block tables (``tables`` always; ``swa_tables`` for
-    ring segments).  Mirrors :func:`decode_self_attention` slot-for-
-    slot: the new K/V lands at the physical home of the dense slot and
-    scores run over the gathered logical view.
+    x: (B,1,D); cache {"k","v"}: (L, NB_phys, bs, KV*hd) stacked pools;
+    paged carries the layer index ``layer`` into them and the block
+    tables (``tables`` always; ``swa_tables`` for ring segments).
+    Mirrors :func:`decode_self_attention` slot-for-slot: the new K/V
+    lands at the physical home of the dense slot and scores run over
+    the gathered logical view.  Returns the updated stacked pools.
     """
     b = x.shape[0]
-    bs = cache["k"].shape[1]
+    layer = paged["layer"]
+    bs = cache["k"].shape[-2]
     max_len = paged["tables"].shape[1] * bs
     q = _proj_q(params, x, cfg)
     k_new, v_new = _proj_kv(params, x, cfg)
@@ -297,11 +316,11 @@ def paged_decode_self_attention(params, x, cache: dict, paged: dict, pos,
     off = slot % bs
     # rows of a decode batch own disjoint blocks; only inactive rows
     # share the scratch block (id 0), whose content is never read
-    k_pool = cache["k"].at[phys, off].set(k_new[:, 0])
-    v_pool = cache["v"].at[phys, off].set(v_new[:, 0])
+    k_pool = cache["k"].at[layer, phys, off].set(_slot_rows(k_new[:, 0]))
+    v_pool = cache["v"].at[layer, phys, off].set(_slot_rows(v_new[:, 0]))
 
-    kg = _paged_gather(k_pool, tables, s)
-    vg = _paged_gather(v_pool, tables, s)
+    kg = _paged_gather(k_pool, layer, tables, cfg, s)
+    vg = _paged_gather(v_pool, layer, tables, cfg, s)
     scores = _gqa_scores(q, kg, cfg)                 # (B,KV,G,1,S)
     valid = _decode_valid(pos, s, ring=(kind == "swa" and bool(cfg.window)))
     mask = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
@@ -318,9 +337,12 @@ def paged_chunk_self_attention(params, x, cache: dict, paged: dict, pos,
     batch dim 1).  Mirrors :func:`chunk_self_attention` branch-for-
     branch: linear segments write-then-mask through the table, SWA
     scores [old ring ∪ chunk keys] with analytic old-ring positions
-    and ring-writes the last ``min(C, W)`` keys."""
+    and ring-writes the last ``min(C, W)`` keys.  Pools are stacked
+    and addressed at ``paged["layer"]``, as in
+    :func:`paged_decode_self_attention`."""
     b, c, _ = x.shape
-    bs = cache["k"].shape[1]
+    layer = paged["layer"]
+    bs = cache["k"].shape[-2]
     max_len = paged["tables"].shape[1] * bs
     q = _proj_q(params, x, cfg)
     k_new, v_new = _proj_kv(params, x, cfg)
@@ -335,8 +357,8 @@ def paged_chunk_self_attention(params, x, cache: dict, paged: dict, pos,
         w = min(cfg.window, max_len)
         j = jnp.arange(w)[None, :]
         p_old = pos[:, None] - w + (j - pos[:, None]) % w      # (B,W)
-        k_old = _paged_gather(cache["k"], tables, w)
-        v_old = _paged_gather(cache["v"], tables, w)
+        k_old = _paged_gather(cache["k"], layer, tables, cfg, w)
+        v_old = _paged_gather(cache["v"], layer, tables, cfg, w)
         k_all = jnp.concatenate([k_old, k_new], axis=1)
         v_all = jnp.concatenate([v_old, v_new], axis=1)
         kpos = jnp.concatenate(
@@ -351,18 +373,18 @@ def paged_chunk_self_attention(params, x, cache: dict, paged: dict, pos,
         slots = positions[:, -keep:] % w
         phys = tables[bidx, slots // bs]
         off = slots % bs
-        k = cache["k"].at[phys, off].set(k_new[:, -keep:])
-        v = cache["v"].at[phys, off].set(v_new[:, -keep:])
+        k = cache["k"].at[layer, phys, off].set(_slot_rows(k_new[:, -keep:]))
+        v = cache["v"].at[layer, phys, off].set(_slot_rows(v_new[:, -keep:]))
         return out, {"k": k, "v": v}
 
     tables = paged["tables"]
     slots = jnp.minimum(positions, max_len - 1)
     phys = tables[bidx, slots // bs]
     off = slots % bs
-    k = cache["k"].at[phys, off].set(k_new)
-    v = cache["v"].at[phys, off].set(v_new)
-    kg = _paged_gather(k, tables)                    # (B, max_len, KV, hd)
-    vg = _paged_gather(v, tables)
+    k = cache["k"].at[layer, phys, off].set(_slot_rows(k_new))
+    v = cache["v"].at[layer, phys, off].set(_slot_rows(v_new))
+    kg = _paged_gather(k, layer, tables, cfg)        # (B, max_len, KV, hd)
+    vg = _paged_gather(v, layer, tables, cfg)
     scores = _gqa_scores(q, kg, cfg)
     kpos = jnp.arange(max_len)[None, None, None, :]
     valid = kpos <= qpos

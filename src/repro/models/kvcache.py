@@ -10,11 +10,11 @@ entry per segment, leaves with a leading layer dim):
   slot-granular (`serving/engine.py`'s dense engines).
 * **Paged** (:class:`PagedCache` + :meth:`PagedCache.struct`) —
   fixed-size blocks in a shared pool: attn/swa/cross leaves are
-  ``(n_layers, num_physical_blocks, block_size, kv_heads, hd)`` and a
-  request's logical slot ``s`` lives at
-  ``(tables[row, s // block_size], s % block_size)``.  Admission is
-  block-granular (token-level), so mixed-length workloads share the
-  pool (`serving/engine.py`'s paged engines).
+  ``(n_layers, num_physical_blocks, block_size, kv_heads * hd)`` (one
+  row per slot, its heads side by side) and a request's logical slot
+  ``s`` lives at ``(tables[row, s // block_size], s % block_size)``.
+  Admission is block-granular (token-level), so mixed-length workloads
+  share the pool (`serving/engine.py`'s paged engines).
 
 Cache layout invariants (relied on across models/serving/kernels):
 
@@ -254,7 +254,11 @@ class PagedCache:
         Mirrors :func:`cache_struct` segment-for-segment; attn/swa/cross
         leaves swap the per-slot batch rows for
         ``(group_blocks + 1, block_size)`` physical pools (+1 for the
-        scratch block), SSM leaves keep ``max_rows`` state rows.
+        scratch block) whose slots are rows of ``kv_heads * hd``, SSM
+        leaves keep ``max_rows`` state rows.  A slot row keeps the TPU's
+        tiles dense for the scatter that writes it and the block gather
+        that reads it (128-lane tiles would pad a ``(5, 64)`` slot
+        6.4x; SERVING.md §Donation).
         """
         cfg = self.cfg
         segs = (build_segments(cfg) if layers is None
@@ -269,14 +273,14 @@ class PagedCache:
             if seg.kind in ("attn", "swa"):
                 nb = (nb_swa if (seg.kind == "swa" and cfg.window)
                       else nb_attn)
-                c = {"k": jnp.zeros((n, nb, bs, kvh, hd), dtype),
-                     "v": jnp.zeros((n, nb, bs, kvh, hd), dtype)}
+                c = {"k": jnp.zeros((n, nb, bs, kvh * hd), dtype),
+                     "v": jnp.zeros((n, nb, bs, kvh * hd), dtype)}
                 if cfg.is_encoder_decoder:
-                    c["xk"] = jnp.zeros((n, nb_cross, bs, kvh, hd), dtype)
+                    c["xk"] = jnp.zeros((n, nb_cross, bs, kvh * hd), dtype)
                     c["xv"] = jnp.zeros_like(c["xk"])
             elif seg.kind == "cross":
-                c = {"xk": jnp.zeros((n, nb_cross, bs, kvh, hd), dtype),
-                     "xv": jnp.zeros((n, nb_cross, bs, kvh, hd), dtype)}
+                c = {"xk": jnp.zeros((n, nb_cross, bs, kvh * hd), dtype),
+                     "xv": jnp.zeros((n, nb_cross, bs, kvh * hd), dtype)}
             elif seg.kind == "mamba1":
                 di, ds = cfg.d_inner_eff, cfg.ssm_state
                 c = {"h": jnp.zeros((n, self.max_rows, di, ds), jnp.float32),

@@ -87,8 +87,10 @@ def block_apply(params: dict, x, *, kind: str, cfg, mode: str,
     """Apply one block.  Returns (x, cache_out, aux).
 
     ``paged`` switches the decode/chunk cache paths to block-pool
-    addressing (block tables from ``models.kvcache.PagedCache.meta``);
-    train/prefill modes are dense-only.
+    addressing (block tables from ``models.kvcache.PagedCache.meta``,
+    plus ``layer``, this layer's index into the segment's stacked
+    pools, which ``cache`` then holds whole); train/prefill modes are
+    dense-only.
 
     ``qformat`` tags the weight format the params were packed to
     ("int8"/"int4", `models/quantize.py`).  Numeric dispatch is
@@ -130,7 +132,7 @@ def block_apply(params: dict, x, *, kind: str, cfg, mode: str,
             hx = rmsnorm(params["ln_x"], x, cfg.norm_eps)
             if mode in ("decode", "chunk"):
                 xkv = (attn_mod.paged_cross_view(cache, paged,
-                                                 cfg.encoder_seq)
+                                                 cfg.encoder_seq, cfg)
                        if paged is not None
                        else {"k": cache["xk"], "v": cache["xv"]})
             else:
@@ -143,7 +145,7 @@ def block_apply(params: dict, x, *, kind: str, cfg, mode: str,
         if mode in ("decode", "chunk"):
             if paged is not None:
                 src = cfg.n_image_tokens or cfg.encoder_seq
-                xkv = attn_mod.paged_cross_view(cache, paged, src)
+                xkv = attn_mod.paged_cross_view(cache, paged, src, cfg)
             else:
                 xkv = {"k": cache["xk"], "v": cache["xv"]}
             cache_out = cache
@@ -300,7 +302,12 @@ def apply_segments(blocks, x, *, cfg, mode, segs=None, positions=None,
     audit, where scan bodies would be counted once by cost_analysis).
     paged: block-table metadata dict for paged decode/chunk caches —
     shared by every segment (tables are per-request, not per-layer), so
-    it rides in the closure, not through the scan.
+    it rides in the closure, not through the scan.  A segment's paged
+    pools (attn/swa/cross leaves) are the scan's *carry*, not its
+    ``xs``/``ys``: each layer gets its index as ``paged["layer"]`` and
+    writes and reads its slots of the stacked pool in place, so no
+    layer is sliced out of the pool or stacked back into it.  Dense
+    caches and SSM state rows keep the ``xs``/``ys`` path.
     qformat: weight-format tag for packed params (models/quantize.py) —
     rides in the closure like ``paged``; packed {"q","s"} leaves stack
     and slice through the scan exactly like dense weights.
@@ -322,7 +329,10 @@ def apply_segments(blocks, x, *, cfg, mode, segs=None, positions=None,
         if remat:
             apply_one = jax.checkpoint(apply_one)
 
-        if seg.length == 1 or seg.shared:
+        if (paged is not None and cache is not None
+                and seg.kind in ("attn", "swa", "cross")):
+            x, c_out, aux = _apply_pooled(params, x, cache, seg, paged, kw)
+        elif seg.length == 1 or seg.shared:
             c0 = (None if cache is None
                   else jax.tree.map(lambda a: a[0], cache))
             x, c_out, aux = apply_one(params, x, c0)
@@ -350,3 +360,27 @@ def apply_segments(blocks, x, *, cfg, mode, segs=None, positions=None,
         aux_total = {k: aux_total[k] + aux[k] for k in aux_total}
         new_caches.append(c_out)
     return x, new_caches, aux_total
+
+
+def _apply_pooled(params, x, pools, seg, paged, kw):
+    """One segment against its stacked paged pools: the pools ride in
+    the layer scan's carry and layer ``l`` addresses them at
+    ``paged["layer"] = l`` (`models/attention.py`).  Returns
+    (x, pools, aux) like the ``xs``/``ys`` branches of
+    :func:`apply_segments`.  Always a scan: ``unroll`` serves the
+    roofline audit, which runs dense caches."""
+    def apply_one(p, xx, c, layer):
+        return block_apply(p, xx, cache=c,
+                           **dict(kw, paged=dict(paged, layer=layer)))
+
+    if seg.length == 1 or seg.shared:
+        return apply_one(params, x, pools, 0)
+
+    def body(carry, slices):
+        xx, c = carry
+        p, layer = slices
+        y, c, aux = apply_one(p, xx, c, layer)
+        return (y, c), aux
+    (x, pools), aux_stack = jax.lax.scan(
+        body, (x, pools), (params, jnp.arange(seg.length)))
+    return x, pools, jax.tree.map(jnp.sum, aux_stack)

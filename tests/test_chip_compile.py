@@ -14,6 +14,7 @@ every worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,17 +88,33 @@ def smollm(one_chip):
     return model, pc, params, caches, i32
 
 
-def test_decode_macro_step_fits_v5e_hbm(smollm):
-    """The K=16 paged macro-step at 8 rows x 2048 compiles, and its
-    arguments plus scratch fit one chip.  The scratch grows with
-    K x rows x max_len (ROADMAP A3): 32 rows x 2048 does not fit."""
+def _macro_step(smollm):
+    """The K=16 paged macro-step at 8 rows x 2048, compiled."""
     model, pc, params, caches, i32 = smollm
     k = ENGINE_SHAPE["decode_steps"]
     fn = jax.jit(functools.partial(model.decode_steps, k=k),
                  donate_argnums=(1,))
     batch = {"token": i32(ROWS, 1), "pos": i32(ROWS), "budget": i32(ROWS)}
     meta = {"tables": i32(ROWS, pc.nb_logical)}
-    mem = fn.lower(params, caches, batch, meta).compile().memory_analysis()
+    return fn.lower(params, caches, batch, meta).compile()
+
+
+def _prefill_chunk(smollm):
+    """One 256-token ``paged_prefill_chunk``, compiled."""
+    model, pc, params, caches, i32 = smollm
+    fn = jax.jit(model.paged_prefill_chunk, donate_argnums=(1,))
+    chunk = ENGINE_SHAPE["prefill_chunk"]
+    return fn.lower(params, caches, i32(1, chunk), i32(), i32(),
+                    {"tables": i32(1, pc.nb_logical)}).compile()
+
+
+def test_decode_macro_step_fits_v5e_hbm(smollm):
+    """The K=16 paged macro-step at 8 rows x 2048 compiles, and its
+    arguments plus scratch fit one chip.  The scratch is mostly the
+    loops' row-major copy of the pools, converted once a call (SERVING.md
+    §Donation): it grows with rows x max_len, not with K, and 64 rows x
+    2048 fit (12.0 GiB)."""
+    mem = _macro_step(smollm).memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, (mem.argument_size_in_bytes,
                                   mem.temp_size_in_bytes)
@@ -128,14 +145,75 @@ def test_pipelined_macro_step_fits_v5e_hbm(one_chip, smollm):
 
 
 def test_paged_prefill_chunk_compiles(smollm):
-    model, pc, params, caches, i32 = smollm
-    fn = jax.jit(model.paged_prefill_chunk, donate_argnums=(1,))
-    chunk = ENGINE_SHAPE["prefill_chunk"]
-    compiled = fn.lower(params, caches, i32(1, chunk), i32(), i32(),
-                        {"tables": i32(1, pc.nb_logical)}).compile()
-    mem = compiled.memory_analysis()
+    mem = _prefill_chunk(smollm).memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def _computations(hlo: str) -> dict:
+    """Optimized HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _in_loops(hlo: str):
+    """Instruction lines of every ``while`` body, with the fusions and
+    nested loops those bodies call."""
+    comps = _computations(hlo)
+    todo = [b for lines in comps.values() for line in lines
+            if " while(" in line
+            for b in re.findall(r"body=%?([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+            yield line
+
+
+#: (program, limit on its compiled scratch in bytes or None).  The
+#: K=16 macro-step's scratch was 6,512,488,960 B while each layer
+#: sliced its pool out and stacked it back.
+POOL_PROGRAMS = {"decode_macro_step": (_macro_step, 2.5 * 2**30),
+                 "prefill_chunk": (_prefill_chunk, None)}
+
+
+@pytest.mark.parametrize("program", sorted(POOL_PROGRAMS))
+def test_loops_write_pools_in_place(smollm, program):
+    """No loop body of the compiled program copies, slices or updates
+    a whole K/V pool or one layer of it: the layer scan carries the
+    stacked pools and each layer scatters and gathers its own slots
+    (``transformer.apply_segments``).  The pool's entry and exit
+    layout copies, once per call, sit outside the loops."""
+    model, _, _, caches, _ = smollm
+    compile_fn, temp_limit = POOL_PROGRAMS[program]
+    compiled = compile_fn(smollm)
+    stored = caches[0]["k"].shape                  # (L, NB, bs, KV*hd)
+    heads = (model.cfg.n_kv_heads, model.cfg.head_dim)
+    shapes = {s for full in (stored, (*stored[:-1], *heads))
+              for s in (full, (1, *full[1:]), full[1:])}
+    pat = re.compile(r"= bf16\[([\d,]+)\]\{[^}]*\} "
+                     r"(copy|dynamic-slice|dynamic-update-slice)\(")
+    hits = [line.strip()[:160] for line in _in_loops(compiled.as_text())
+            if (m := pat.search(line))
+            and tuple(map(int, m.group(1).split(","))) in shapes]
+    assert not hits, hits
+    if temp_limit is not None:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < temp_limit, temp
 
 
 # SmolLM-360M widths: 15 query / 5 KV heads of 64, d_model 960, d_ff
