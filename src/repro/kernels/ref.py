@@ -47,24 +47,25 @@ def decode_attention_ref(q, k_cache, v_cache, pos, *,
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, pos, *,
-                               scale: float | None = None):
+def paged_decode_attention_ref(q, k_pool, v_pool, tables, lengths, layer,
+                               *, kv_heads: int):
     """Paged flash-decode oracle: gather the logical view, then score.
 
-    q: (B,H,D); pools: (KV, NB, bs, D) physical block pools;
-    block_tables: (B, nb) int32 physical ids per logical block
-    (unallocated entries may point anywhere in range — they are masked
-    by ``pos``); pos: (B,) valid-length-1 indices.
+    q: (B,H,D); pools: (L, NB, bs, W) stacked block pools, a slot's
+    ``kv_heads`` heads side by side in its first KV*D columns; tables:
+    (B, nb) int32 physical ids per logical block (entries past a row's
+    length may point anywhere in range); lengths: (B,) slots each row
+    attends to (0: zeros); layer: the pools' layer to read.
     """
-    kv, nb_phys, bs, d = k_pool.shape
-    b = q.shape[0]
-    nb = block_tables.shape[1]
-    # (KV, NB, bs, D)[:, tables] -> (KV, B, nb, bs, D) -> (B, KV, S, D)
-    kg = jnp.moveaxis(k_pool[:, block_tables], 1, 0).reshape(
-        b, kv, nb * bs, d)
-    vg = jnp.moveaxis(v_pool[:, block_tables], 1, 0).reshape(
-        b, kv, nb * bs, d)
-    return decode_attention_ref(q, kg, vg, pos, scale=scale)
+    b, _, d = q.shape
+    bs, kv, nb = k_pool.shape[2], kv_heads, tables.shape[1]
+
+    def view(pool):   # (B, nb, bs, W) -> (B, KV, S, D)
+        return jnp.moveaxis(pool[layer, tables][..., :kv * d].reshape(
+            b, nb * bs, kv, d), 2, 1)
+
+    out = decode_attention_ref(q, view(k_pool), view(v_pool), lengths - 1)
+    return jnp.where((lengths > 0)[:, None, None], out, 0).astype(q.dtype)
 
 
 def selective_scan_ref(dt, b_mat, c_mat, x, a_neg, h0):

@@ -14,21 +14,29 @@ scratch block.
 
 The paged paths take a segment's *stacked* pools (leading layer dim)
 and the layer's index in ``paged["layer"]``: the layer scan carries the
-pools (`src/repro/models/transformer.py::apply_segments`), each layer
-scatters its new K/V at ``pool.at[layer, phys, off]`` and gathers its
-view with one ``pool[layer, tables]``; a pool stores each slot as one
-row of ``kv_heads * head_dim``.  A loop carry updated by indexed
+pools (`src/repro/models/transformer.py::apply_segments`) and each
+layer scatters its new K/V at ``pool.at[layer, phys, off]``; a pool
+stores each slot as one row of ``kv_heads * head_dim``, padded to whole
+128-lane tiles (:func:`slot_width`).  A loop carry updated by indexed
 scatters is what keeps the writes in place; buffer donation alone did
 not (a pool threaded through the scan as ``xs``/``ys`` was sliced out,
 written and stacked back, whole, every layer of every iteration).
+
+Reads: decode on the TPU runs the paged decode kernel
+(`src/repro/kernels/decode_attention.py`), which copies each row's live
+blocks straight from the carried pool; decode elsewhere, the prefill
+chunk, verify and cross-attention gather a layer's view with one
+``pool[layer, tables]``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention import paged_decode_attention_pallas
 from repro.models.layers import _dense_init, rotary
 from repro.models.quantize import qdot
 from repro.sharding.specs import constrain
@@ -85,13 +93,17 @@ def _gqa_scores(q, k, cfg):
     return scores * (hd ** -0.5)
 
 
-def _gqa_out(probs, v, params, cfg, out_dtype):
-    """probs: (B,KV,G,Q,S), v: (B,S,KV,hd) -> (B,Q,D)."""
+def _gqa_mix(probs, v, cfg, out_dtype):
+    """probs: (B,KV,G,Q,S), v: (B,S,KV,hd) -> (B,Q,H*hd) heads."""
     b = probs.shape[0]
     out = jnp.einsum("bngqs,bsnh->bqngh", probs, v.astype(jnp.float32))
     out = out.reshape(b, out.shape[1], cfg.n_heads * cfg.head_dim)
-    out = out.astype(out_dtype)
-    return qdot(out, params["wo"])
+    return out.astype(out_dtype)
+
+
+def _gqa_out(probs, v, params, cfg, out_dtype):
+    """probs: (B,KV,G,Q,S), v: (B,S,KV,hd) -> (B,Q,D)."""
+    return qdot(_gqa_mix(probs, v, cfg, out_dtype), params["wo"])
 
 
 def _causal_mask(qlen: int, klen: int, q_offset, window: int = 0):
@@ -246,20 +258,34 @@ def chunk_self_attention(params, x, cache: dict, pos, cfg,
 # stale/unallocated slot is masked exactly where the dense path masks
 # its zero-initialised slots.
 # ----------------------------------------------------------------------
+LANES = 128  # a TPU vector register's lane count
+
+
+def slot_width(cfg) -> int:
+    """Width of one pool slot row: the slot's KV heads side by side,
+    padded with zeros to whole 128-lane tiles.  The TPU stores a row in
+    128-lane tiles anyway, so the padding costs no HBM there, and it
+    lets the decode kernel copy a block whole."""
+    return -(-cfg.n_kv_heads * cfg.head_dim // LANES) * LANES
+
+
 def _paged_gather(pool, layer, tables, cfg, take: Optional[int] = None):
-    """Layer ``layer`` of the stacked pool (L, NB, bs, KV*hd) gathered
+    """Layer ``layer`` of the stacked pool (L, NB, bs, W) gathered
     through tables (B, nb) into the logical view (B, nb*bs, KV, hd),
     optionally truncated to ``take`` slots (SWA ring / cross source
     shorter than the block grid)."""
-    g = pool[layer, tables]                          # (B, nb, bs, KV*hd)
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
+    g = pool[layer, tables][..., :kvh * hd]          # (B, nb, bs, KV*hd)
     b, nb, bs = g.shape[:3]
-    g = g.reshape(b, nb * bs, cfg.n_kv_heads, cfg.head_dim)
+    g = g.reshape(b, nb * bs, kvh, hd)
     return g if take is None else g[:, :take]
 
 
-def _slot_rows(kv):
-    """(..., KV, hd) new keys or values -> (..., KV*hd) pool slot rows."""
-    return kv.reshape(*kv.shape[:-2], -1)
+def _slot_rows(kv, cfg):
+    """(..., KV, hd) new keys or values -> (..., W) pool slot rows."""
+    rows = kv.reshape(*kv.shape[:-2], -1)
+    pad = slot_width(cfg) - rows.shape[-1]
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
 
 
 def _decode_valid(pos, s: int, ring: bool):
@@ -287,12 +313,13 @@ def paged_decode_self_attention(params, x, cache: dict, paged: dict, pos,
                                 cfg, kind: str) -> Tuple[jnp.ndarray, dict]:
     """One-token decode against paged block pools.
 
-    x: (B,1,D); cache {"k","v"}: (L, NB_phys, bs, KV*hd) stacked pools;
-    paged carries the layer index ``layer`` into them and the block
-    tables (``tables`` always; ``swa_tables`` for ring segments).
-    Mirrors :func:`decode_self_attention` slot-for-slot: the new K/V
-    lands at the physical home of the dense slot and scores run over
-    the gathered logical view.  Returns the updated stacked pools.
+    x: (B,1,D); cache {"k","v"}: (L, NB_phys, bs, W) stacked pools
+    (:func:`slot_width`); paged carries the layer index ``layer`` into
+    them and the block tables (``tables`` always; ``swa_tables`` for
+    ring segments).  Mirrors :func:`decode_self_attention`
+    slot-for-slot: the new K/V lands at the physical home of the dense
+    slot, then :func:`_paged_decode_attend` scores the row's slots.
+    Returns the updated stacked pools.
     """
     b = x.shape[0]
     layer = paged["layer"]
@@ -316,18 +343,60 @@ def paged_decode_self_attention(params, x, cache: dict, paged: dict, pos,
     off = slot % bs
     # rows of a decode batch own disjoint blocks; only inactive rows
     # share the scratch block (id 0), whose content is never read
-    k_pool = cache["k"].at[layer, phys, off].set(_slot_rows(k_new[:, 0]))
-    v_pool = cache["v"].at[layer, phys, off].set(_slot_rows(v_new[:, 0]))
+    k_pool = cache["k"].at[layer, phys, off].set(
+        _slot_rows(k_new[:, 0], cfg))
+    v_pool = cache["v"].at[layer, phys, off].set(
+        _slot_rows(v_new[:, 0], cfg))
 
+    heads = _paged_decode_attend(
+        q, k_pool, v_pool, tables, pos, jnp.asarray(layer, jnp.int32),
+        cfg=cfg, s=s, ring=(kind == "swa" and bool(cfg.window)),
+        dtype=x.dtype)
+    return qdot(heads, params["wo"]), {"k": k_pool, "v": v_pool}
+
+
+def _gathered_decode(q, k_pool, v_pool, tables, pos, layer, *, cfg, s,
+                     ring, dtype):
+    """Decode attention over the gathered logical view of every row's
+    whole table, masked by :func:`_decode_valid`: (B,1,H*hd) heads."""
     kg = _paged_gather(k_pool, layer, tables, cfg, s)
     vg = _paged_gather(v_pool, layer, tables, cfg, s)
     scores = _gqa_scores(q, kg, cfg)                 # (B,KV,G,1,S)
-    valid = _decode_valid(pos, s, ring=(kind == "swa" and bool(cfg.window)))
-    mask = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
+    mask = jnp.where(_decode_valid(pos, s, ring), 0.0,
+                     NEG_INF).astype(jnp.float32)
     scores = scores + mask[:, None, None, None, :]
     probs = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(probs, vg, params, cfg, x.dtype)
-    return out, {"k": k_pool, "v": v_pool}
+    return _gqa_mix(probs, vg, cfg, dtype)
+
+
+def _kernel_decode(q, k_pool, v_pool, tables, pos, layer, *, cfg, s,
+                   dtype, interpret: bool = False):
+    """The same heads from the paged decode kernel, which reads each
+    row's live blocks only: slots ``[0, min(pos + 1, s))``, what
+    :func:`_decode_valid` admits on the linear table and on the SWA
+    ring alike.  A row whose first table entry is the scratch block
+    holds no request (`models/kvcache.py`): it reads nothing, and its
+    heads, which nothing reads, are zeros."""
+    b, _, h, hd = q.shape
+    lengths = jnp.where(tables[:, 0] > 0, jnp.minimum(pos + 1, s), 0)
+    out = paged_decode_attention_pallas(
+        q[:, 0], k_pool, v_pool, tables, lengths, layer,
+        kv_heads=cfg.n_kv_heads, interpret=interpret)
+    return out.reshape(b, 1, h * hd).astype(dtype)
+
+
+def _paged_decode_attend(q, k_pool, v_pool, tables, pos, layer, *, cfg, s,
+                         ring, dtype):
+    """Decode attention of q (B,1,H,hd) over layer ``layer`` of the
+    stacked pools -> (B,1,H*hd) heads in ``dtype``.  A program lowered
+    for the TPU runs :func:`_kernel_decode`; any other platform runs
+    :func:`_gathered_decode`, whose CPU token streams the engines'
+    parity tests pin."""
+    return jax.lax.platform_dependent(
+        q, k_pool, v_pool, tables, pos, layer,
+        tpu=functools.partial(_kernel_decode, cfg=cfg, s=s, dtype=dtype),
+        default=functools.partial(_gathered_decode, cfg=cfg, s=s,
+                                  ring=ring, dtype=dtype))
 
 
 def paged_chunk_self_attention(params, x, cache: dict, paged: dict, pos,
@@ -373,16 +442,18 @@ def paged_chunk_self_attention(params, x, cache: dict, paged: dict, pos,
         slots = positions[:, -keep:] % w
         phys = tables[bidx, slots // bs]
         off = slots % bs
-        k = cache["k"].at[layer, phys, off].set(_slot_rows(k_new[:, -keep:]))
-        v = cache["v"].at[layer, phys, off].set(_slot_rows(v_new[:, -keep:]))
+        k = cache["k"].at[layer, phys, off].set(
+            _slot_rows(k_new[:, -keep:], cfg))
+        v = cache["v"].at[layer, phys, off].set(
+            _slot_rows(v_new[:, -keep:], cfg))
         return out, {"k": k, "v": v}
 
     tables = paged["tables"]
     slots = jnp.minimum(positions, max_len - 1)
     phys = tables[bidx, slots // bs]
     off = slots % bs
-    k = cache["k"].at[layer, phys, off].set(_slot_rows(k_new))
-    v = cache["v"].at[layer, phys, off].set(_slot_rows(v_new))
+    k = cache["k"].at[layer, phys, off].set(_slot_rows(k_new, cfg))
+    v = cache["v"].at[layer, phys, off].set(_slot_rows(v_new, cfg))
     kg = _paged_gather(k, layer, tables, cfg)        # (B, max_len, KV, hd)
     vg = _paged_gather(v, layer, tables, cfg)
     scores = _gqa_scores(q, kg, cfg)

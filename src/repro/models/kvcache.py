@@ -10,8 +10,9 @@ entry per segment, leaves with a leading layer dim):
   slot-granular (`serving/engine.py`'s dense engines).
 * **Paged** (:class:`PagedCache` + :meth:`PagedCache.struct`) —
   fixed-size blocks in a shared pool: attn/swa/cross leaves are
-  ``(n_layers, num_physical_blocks, block_size, kv_heads * hd)`` (one
-  row per slot, its heads side by side) and a request's logical slot
+  ``(n_layers, num_physical_blocks, block_size, width)`` (one row per
+  slot, its heads side by side, ``width`` = ``kv_heads * hd`` padded to
+  whole 128-lane tiles) and a request's logical slot
   ``s`` lives at ``(tables[row, s // block_size], s % block_size)``.
   Admission is block-granular (token-level), so mixed-length workloads
   share the pool (`serving/engine.py`'s paged engines).
@@ -21,7 +22,9 @@ Cache layout invariants (relied on across models/serving/kernels):
 * physical block 0 of every paged pool is the **scratch block**: never
   allocated, it absorbs the writes of inactive decode rows; block-table
   entries of unallocated logical blocks point at scratch, and every
-  read through them is masked by position;
+  read through them is masked by position.  An admitted row owns its
+  first logical block, so a row whose table starts at scratch holds no
+  request: the TPU decode kernel reads nothing for it;
 * stale attn/swa KV needs no zeroing on block reuse — attention masks
   slots above ``pos`` (and the SWA ring is fully rewritten before its
   all-slots-valid regime at ``pos >= window - 1``);
@@ -56,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import slot_width
 from repro.models.transformer import build_segments, segment_range
 
 
@@ -254,16 +258,18 @@ class PagedCache:
         Mirrors :func:`cache_struct` segment-for-segment; attn/swa/cross
         leaves swap the per-slot batch rows for
         ``(group_blocks + 1, block_size)`` physical pools (+1 for the
-        scratch block) whose slots are rows of ``kv_heads * hd``, SSM
-        leaves keep ``max_rows`` state rows.  A slot row keeps the TPU's
-        tiles dense for the scatter that writes it and the block gather
-        that reads it (128-lane tiles would pad a ``(5, 64)`` slot
-        6.4x; SERVING.md §Donation).
+        scratch block) whose slots are rows of ``kv_heads * hd``, padded
+        to whole 128-lane tiles (``attention.slot_width``), SSM leaves
+        keep ``max_rows`` state rows.  A slot row keeps the TPU's tiles
+        dense for the scatter that writes it and the block copies that
+        read it (128-lane tiles would pad a ``(5, 64)`` slot 6.4x;
+        SERVING.md §Donation); the padding is what those tiles hold
+        anyway, and it lets the decode kernel copy a block whole.
         """
         cfg = self.cfg
         segs = (build_segments(cfg) if layers is None
                 else segment_range(cfg, *layers))
-        bs, kvh, hd = self.block_size, cfg.n_kv_heads, cfg.head_dim
+        bs, w = self.block_size, slot_width(cfg)
         nb_attn = self._groups["attn"] + 1
         nb_swa = self._groups["swa"] + 1
         nb_cross = self._groups["cross"] + 1
@@ -273,14 +279,14 @@ class PagedCache:
             if seg.kind in ("attn", "swa"):
                 nb = (nb_swa if (seg.kind == "swa" and cfg.window)
                       else nb_attn)
-                c = {"k": jnp.zeros((n, nb, bs, kvh * hd), dtype),
-                     "v": jnp.zeros((n, nb, bs, kvh * hd), dtype)}
+                c = {"k": jnp.zeros((n, nb, bs, w), dtype),
+                     "v": jnp.zeros((n, nb, bs, w), dtype)}
                 if cfg.is_encoder_decoder:
-                    c["xk"] = jnp.zeros((n, nb_cross, bs, kvh * hd), dtype)
+                    c["xk"] = jnp.zeros((n, nb_cross, bs, w), dtype)
                     c["xv"] = jnp.zeros_like(c["xk"])
             elif seg.kind == "cross":
-                c = {"xk": jnp.zeros((n, nb_cross, bs, kvh * hd), dtype),
-                     "xv": jnp.zeros((n, nb_cross, bs, kvh * hd), dtype)}
+                c = {"xk": jnp.zeros((n, nb_cross, bs, w), dtype),
+                     "xv": jnp.zeros((n, nb_cross, bs, w), dtype)}
             elif seg.kind == "mamba1":
                 di, ds = cfg.d_inner_eff, cfg.ssm_state
                 c = {"h": jnp.zeros((n, self.max_rows, di, ds), jnp.float32),

@@ -20,6 +20,9 @@ instrumentation (the per-K macro-step jits are built lazily).
 Counter keys are the ``_jits`` names (``decode{k}``, ``verify{s}``,
 ``prefill``, ``reset``, ``cow``); pipelined engines' per-stage programs
 are prefixed ``s{i}.`` and a ModelDraft provider's programs ``draft.``.
+A paged engine's ``decode{k}`` calls also add up the KV blocks its
+decode attention reads against a whole-table gather
+(:meth:`EngineCounts.count_kv_blocks`).
 tests/test_engine_macro.py pins the dispatches-per-token regression;
 benchmarks/engine_bench.py and benchmarks/spec_bench.py report the
 same numbers per engine/K cell.
@@ -64,6 +67,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 import jax
+import numpy as np
 
 #: the engine's own spans (program spans carry their ``_jits`` names)
 SPAN_NAMES = ("engine.step", "engine.admit", "engine.prefill_prep",
@@ -279,8 +283,14 @@ class DispatchCounter(dict):
         self.ec.raw[key] = fn
         ec = self.ec
 
+        decode_k = (int(name[len("decode"):])
+                    if not self.prefix and name.startswith("decode")
+                    and name[len("decode"):].isdigit() else 0)
+
         def counted(*args, _fn=fn, _key=key, **kw):
             ec.counts[_key] += 1
+            if decode_k:
+                ec.count_kv_blocks(decode_k)
             if ec.calls is not None:
                 ec.calls.append((_key, ec.tracing, args[2:4]))
             with self.owner.trace.span(_key):
@@ -307,6 +317,27 @@ class EngineCounts:
         self.raw: dict = {}  # jit name -> underlying (unwrapped) callable
         self.calls: Optional[list] = None
         self.tracing = False
+        self.kv_blocks_live = 0
+        self.kv_blocks_table = 0
+
+    def count_kv_blocks(self, k: int):
+        """Before a paged engine's ``decode{k}`` call: add the attn-table
+        KV blocks one layer's decode attention reads over the ``k``
+        iterations.  ``kv_blocks_live``: each row that holds a request,
+        ``ceil(min(pos + 1 + i, max_len) / block_size)`` at iteration
+        ``i``, what the TPU decode kernel copies (a row that finishes
+        inside the scan is counted as if it went on; a free row reads
+        nothing).  ``kv_blocks_table``: every row's whole table, what a
+        gather of the logical view reads.  Their ratio is the share of
+        the gather's KV traffic the kernel keeps."""
+        pc = getattr(self.engine, "pc", None)
+        if pc is None:
+            return
+        rows = [i for i, r in enumerate(self.engine.rows) if r is not None]
+        slots = np.minimum(self.engine.pos[rows, None] + 1 + np.arange(k),
+                           pc.max_len)
+        self.kv_blocks_live += int((-(-slots // pc.block_size)).sum())
+        self.kv_blocks_table += k * pc.max_rows * pc.nb_logical
 
     @property
     def decode_dispatches(self) -> int:

@@ -218,9 +218,10 @@ def test_loops_write_pools_in_place(smollm, program):
 
 # SmolLM-360M widths: 15 query / 5 KV heads of 64, d_model 960, d_ff
 # 2560, 8 decode rows over 2048 positions in 16-token blocks (1024 pool
-# blocks + scratch).  The selective scan takes Falcon-Mamba-7B's
-# d_inner 8192 and d_state 16 over a 256-token prefill chunk, in f32 as
-# models/ssm.py feeds it.
+# blocks + scratch); the paged decode kernel reads the 32 layers'
+# stacked pool, whose 320-wide slots are stored 384 wide.  The
+# selective scan takes Falcon-Mamba-7B's d_inner 8192 and d_state 16
+# over a 256-token prefill chunk, in f32 as models/ssm.py feeds it.
 _BF, _F32, _I32 = jnp.bfloat16, jnp.float32, jnp.int32
 KERNELS = {
     "flash_attention": (flash_attention_pallas, [
@@ -229,9 +230,11 @@ KERNELS = {
     "decode_attention": (decode_attention_pallas, [
         ((8, 15, 64), _BF), ((8, 5, 2048, 64), _BF),
         ((8, 5, 2048, 64), _BF), ((8,), _I32)]),
-    "paged_decode_attention": (paged_decode_attention_pallas, [
-        ((8, 15, 64), _BF), ((5, 1025, 16, 64), _BF),
-        ((5, 1025, 16, 64), _BF), ((8, 128), _I32), ((8,), _I32)]),
+    "paged_decode_attention": (functools.partial(
+        paged_decode_attention_pallas, kv_heads=5), [
+        ((8, 15, 64), _BF), ((32, 1025, 16, 384), _BF),
+        ((32, 1025, 16, 384), _BF), ((8, 128), _I32), ((8,), _I32),
+        ((), _I32)]),
     "rmsnorm": (rmsnorm_pallas, [((8, 960), _BF), ((960,), _BF)]),
     "quant_matmul_int8": (quant_matmul_pallas, [
         ((8, 960), _BF), ((960, 2560), jnp.int8), ((1, 2560), _F32)]),

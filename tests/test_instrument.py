@@ -229,3 +229,28 @@ def test_request_and_step_readers():
     empty = EngineTrace(time.perf_counter)
     empty.close()
     assert empty.longest_step() == {}
+
+
+def test_kv_block_counts_of_paged_decode():
+    """A paged ``decode{k}`` call counts the attn-table blocks each live
+    row's decode attention reads over its k iterations, against every
+    row's whole table; the free row reads none, and a dense engine
+    counts nothing."""
+    cfg = get_smoke_config("smollm-360m")
+    eng = PagedServingEngine(cfg, max_rows=2, max_len=64, block_size=8,
+                             prefill_chunk=4, decode_steps=8)
+    counts = instrument(eng)
+    # 9 prompt tokens: prefill writes 8, the first decode is at pos 8,
+    # and 4 tokens make one decode4 call (slots 9..12: 2 blocks each)
+    eng.submit(Request(id=0, prompt=list(range(1, 10)), max_new_tokens=4))
+    eng.run()
+    assert counts.counts["decode4"] == 1 and counts.decode_dispatches == 1
+    assert counts.kv_blocks_live == 4 * 2
+    assert counts.kv_blocks_table == 4 * 2 * (64 // 8)
+    dense = ServingEngine(cfg, max_batch=2, cache_len=64, prefill_chunk=4,
+                          decode_steps=4)
+    counts = instrument(dense)
+    dense.submit(Request(id=0, prompt=list(range(1, 10)), max_new_tokens=4))
+    dense.run()
+    assert counts.decode_dispatches == 1
+    assert counts.kv_blocks_live == counts.kv_blocks_table == 0

@@ -1,8 +1,11 @@
 """Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode)."""
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import (decode_attention_pallas,
@@ -10,8 +13,12 @@ from repro.kernels.decode_attention import (decode_attention_pallas,
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.selective_scan import selective_scan_pallas
+from repro.models import attention
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+# the TPU interpreter, which runs a kernel's hand-made copies only when
+# it waits on them: a read before its wait sees the buffer's old bytes
+ON_WAIT = pltpu.InterpretParams(dma_execution_mode="on_wait")
 
 
 def _tol(dtype):
@@ -61,39 +68,69 @@ def test_decode_attention(b, h, kv, s, d, dtype):
     assert float(err) < _tol(dtype) * 10, float(err)
 
 
-@pytest.mark.parametrize("b,h,kv,nb,bs,d", [
-    (2, 4, 4, 4, 16, 64),     # MHA
-    (3, 8, 2, 3, 8, 32),      # GQA, odd pool
-    (2, 4, 1, 5, 32, 128),    # MQA, wide head
+def _paged_rows(bs, nb, nb_phys, ring):
+    """Block tables and lengths of seven rows over ``nb`` table blocks:
+    lengths 0, 1, bs, bs+1 and the whole table; a free row on the
+    scratch block 0 (its frozen position still counts slots); a row
+    that shares the full row's first two physical blocks.  On a SWA
+    ring of ``s`` < nb*bs slots the last three have wrapped
+    (length s).  Returns (tables, lengths, pos), ``pos`` a position
+    whose :func:`attention._decode_valid` mask admits those slots."""
+    s = nb * bs - 3 if ring else nb * bs
+    rng = np.random.default_rng(0)
+    ids = rng.permutation(np.arange(1, nb_phys))[:7 * nb].reshape(7, nb)
+    ids[5] = 0
+    ids[6, :2] = ids[4, :2]
+    lengths = np.array([0, 1, bs, bs + 1, s, 2 * bs + 3, 2 * bs + 5])
+    pos = lengths - 1
+    if ring:
+        lengths[4:] = s
+        pos[4:] = s + np.array([0, 5, 11])   # wrapped: every slot valid
+    return (jnp.asarray(ids, jnp.int32), jnp.asarray(lengths, jnp.int32),
+            jnp.asarray(pos, jnp.int32), s)
+
+
+@pytest.mark.parametrize("h,kv,d", [
+    (4, 4, 32),     # MHA
+    (15, 5, 16),    # GQA, SmolLM's 15/5 heads at a small head
+    (4, 1, 64),     # MQA
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention(b, h, kv, nb, bs, d, dtype):
-    """Block-table-gather kernel vs the paged oracle AND vs the dense
-    kernel on the pre-gathered logical view (the two must agree
-    bitwise: paging only changes addressing, never math)."""
-    nb_phys = b * nb + 3   # slack blocks the tables never reference
+@pytest.mark.parametrize("ring", [False, True])
+def test_paged_decode_attention(h, kv, d, dtype, ring):
+    """The paged decode kernel on stacked pools at a nonzero layer
+    against the engines' gather path (``_paged_gather`` + float32
+    softmax) and the ref.py oracle.  Pool columns past the heads and
+    slots past a row's length hold noise that must not leak in; a row
+    of length 0 gets zeros."""
+    cfg = SimpleNamespace(n_heads=h, n_kv_heads=kv, head_dim=d)
+    w = attention.slot_width(cfg)
+    n_layers, layer, bs, nb = 3, 2, 8, 4
+    nb_phys = 7 * nb + 2
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    q = jax.random.normal(ks[0], (b, h, d), dtype)
-    kp = jax.random.normal(ks[1], (kv, nb_phys, bs, d), dtype)
-    vp = jax.random.normal(ks[2], (kv, nb_phys, bs, d), dtype)
-    rng = np.random.default_rng(0)
-    ids = rng.permutation(nb_phys - 1)[: b * nb].reshape(b, nb) + 1
-    tables = jnp.asarray(ids, jnp.int32)
-    pos = jnp.asarray(rng.integers(0, nb * bs, size=b), jnp.int32)
+    q = jax.random.normal(ks[0], (7, h, d), dtype)
+    kp = jax.random.normal(ks[1], (n_layers, nb_phys, bs, w), dtype)
+    vp = jax.random.normal(ks[2], (n_layers, nb_phys, bs, w), dtype)
+    tables, lengths, pos, s = _paged_rows(bs, nb, nb_phys, ring)
 
-    out = paged_decode_attention_pallas(q, kp, vp, tables, pos,
-                                        interpret=True)
-    exp = ref.paged_decode_attention_ref(q, kp, vp, tables, pos)
-    err = jnp.max(jnp.abs(out.astype(jnp.float32)
-                          - exp.astype(jnp.float32)))
-    assert float(err) < _tol(dtype) * 10, float(err)
-
-    kg = jnp.moveaxis(kp[:, tables], 1, 0).reshape(b, kv, nb * bs, d)
-    vg = jnp.moveaxis(vp[:, tables], 1, 0).reshape(b, kv, nb * bs, d)
-    dense = decode_attention_pallas(q, kg, vg, pos, block_s=bs,
-                                    interpret=True)
-    assert float(jnp.max(jnp.abs(
-        out.astype(jnp.float32) - dense.astype(jnp.float32)))) == 0.0
+    # the TPU interpreter's callbacks run JAX ops of their own: let them
+    # finish before this thread dispatches more, or the two can deadlock
+    out = jax.block_until_ready(paged_decode_attention_pallas(
+        q, kp, vp, tables, lengths, layer, kv_heads=kv, chunk=2,
+        interpret=ON_WAIT))
+    assert out.shape == q.shape and out.dtype == q.dtype
+    oracle = ref.paged_decode_attention_ref(q, kp, vp, tables, lengths,
+                                            layer, kv_heads=kv)
+    gathered = attention._gathered_decode(
+        q[:, None], kp, vp, tables, pos, layer, cfg=cfg, s=s, ring=ring,
+        dtype=jnp.float32).reshape(q.shape)
+    live = (lengths > 0)[:, None, None]
+    tol = _tol(dtype) * 10
+    for exp in (oracle, jnp.where(live, gathered, 0)):
+        err = jnp.max(jnp.abs(out.astype(jnp.float32)
+                              - exp.astype(jnp.float32)))
+        assert float(err) < tol, float(err)
+    assert not jnp.any(out[0])
 
 
 @pytest.mark.parametrize("b,t,di,ds", [
